@@ -35,6 +35,8 @@ def main(argv=None) -> None:
         help="also write BENCH_<name>.json artifacts to DIR (default: cwd) "
              "for the sections that support them")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     # (section, run_fn, emits BENCH_<name>.json under --json)
     sections = [

@@ -41,6 +41,7 @@ from repro.core import SortConfig, SortEngine, cost as cost_mod
 from repro.data import mot
 from repro.data.synthetic import (SceneConfig, generate_multiclass_scene,
                                   generate_scene)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import StreamScheduler
 from repro.sharding import lane_mesh
 
@@ -97,14 +98,15 @@ async def _serve(sched, seqs, args) -> int:
                           tracks.boxes, tracks.uid, tracks.emit)
         frames[0] += tracks.num_frames
 
+    # the whole replay is one client's admitted work: bound admission by
+    # it, so every lane can fill instead of shedding past the defaults
+    knobs = dict(ckpt_every=args.ckpt_every, on_result=on_result,
+                 max_pending=max(len(seqs), 1),
+                 per_client_pending=max(len(seqs), 1))
     if args.resume:
-        svc = TrackingService.resume(sched, args.ckpt_dir,
-                                     ckpt_every=args.ckpt_every,
-                                     on_result=on_result)
+        svc = TrackingService.resume(sched, args.ckpt_dir, **knobs)
     else:
-        svc = TrackingService(sched, ckpt_dir=args.ckpt_dir,
-                              ckpt_every=args.ckpt_every,
-                              on_result=on_result)
+        svc = TrackingService(sched, ckpt_dir=args.ckpt_dir, **knobs)
         for name, db, dm, dc, de in seqs:
             await svc.submit(name, db, dm, det_class=dc, det_embed=de)
         if svc.ckpt is not None:
@@ -211,6 +213,7 @@ def main():
             not (args.serve and args.ckpt_dir):
         ap.error("--resume/--kill-after-chunks need --serve and --ckpt-dir")
 
+    enable_compile_cache()
     spec = cost_mod.parse_cost(args.cost, embed_dim=args.embed_dim)
     seqs = load_or_synthesize(args.det_dir, num_classes=args.classes,
                               embed_dim=spec.embed_dim)

@@ -46,12 +46,10 @@ def flatten_with_paths(tree):
     ``save``/``restore`` name leaves by — public so callers serializing
     data-dependent trees (the serving checkpoint, DESIGN.md §11) can
     address leaves consistently."""
-    # jax.tree.flatten_with_path only exists on newer jax; the tree_util
-    # spelling works on every version this repo supports.
-    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     keys = ["/".join(str(getattr(k, "key", getattr(k, "idx", k)))
                      for k in path) for path, _ in flat]
-    return keys, [leaf for _, leaf in flat], jax.tree.structure(tree)
+    return keys, [leaf for _, leaf in flat], treedef
 
 
 _flatten = flatten_with_paths

@@ -70,6 +70,18 @@ def pad_cost_matrix(cost: jnp.ndarray, row_mask: jnp.ndarray, col_mask: jnp.ndar
     return out.at[..., :r, :c].set(block)
 
 
+def _pick(a: jnp.ndarray, hot: jnp.ndarray) -> jnp.ndarray:
+    """``a[k]`` along axis 0, for ``hot = arange(n) == k``: a select and a
+    max-reduction, exact since one entry survives.  The solver indexes by
+    data only through this and ``where`` on ``hot``: under ``vmap`` a
+    dynamic index becomes a gather or scatter over the whole lane batch,
+    which the TPU executes one index at a time."""
+    hot = hot.reshape(hot.shape + (1,) * (a.ndim - hot.ndim))
+    low = (-jnp.inf if jnp.issubdtype(a.dtype, jnp.floating)
+           else jnp.iinfo(a.dtype).min)
+    return jnp.max(jnp.where(hot, a, low), axis=0)
+
+
 def solve(cost: jnp.ndarray) -> jnp.ndarray:
     """Solve one ``[n, n]`` assignment problem.
 
@@ -79,6 +91,7 @@ def solve(cost: jnp.ndarray) -> jnp.ndarray:
     n = cost.shape[-1]
     assert cost.shape == (n, n), cost.shape
     cost = cost.astype(jnp.float32)
+    idx = jnp.arange(n, dtype=jnp.int32)
 
     def solve_row(cur_row, carry):
         u, v, col4row, row4col = carry
@@ -94,8 +107,9 @@ def solve(cost: jnp.ndarray) -> jnp.ndarray:
 
         def body(st):
             i, min_val, sink, spc, path, sr, sc = st
-            sr = sr.at[i].set(True)
-            red = min_val + cost[i, :] - u[i] - v
+            row = idx == i
+            sr = sr | row
+            red = min_val + _pick(cost, row) - _pick(u, row) - v
             upd = (~sc) & (red < spc)
             spc = jnp.where(upd, red, spc)
             path = jnp.where(upd, i, path)
@@ -103,20 +117,24 @@ def solve(cost: jnp.ndarray) -> jnp.ndarray:
             # any minimum keeps Dijkstra invariants and the optimal cost)
             masked = jnp.where(sc, _INF, spc)
             j = jnp.argmin(masked).astype(jnp.int32)
-            min_val = spc[j]
-            sc = sc.at[j].set(True)
-            free = row4col[j] < 0
+            col = idx == j
+            min_val = _pick(spc, col)
+            sc = sc | col
+            owner = _pick(row4col, col)
+            free = owner < 0
             sink = jnp.where(free, j, jnp.int32(-1))
-            i = jnp.where(free, i, row4col[j])
+            i = jnp.where(free, i, owner)
             return i, min_val, sink, spc, path, sr, sc
 
         init = (jnp.int32(cur_row), jnp.float32(0.0), jnp.int32(-1), spc, path, sr, sc)
         _, min_val, sink, spc, path, sr, sc = lax.while_loop(cond, body, init)
 
         # --- dual updates (scipy rectangular_lsap convention) ---
-        u = u.at[cur_row].add(min_val)
-        others = sr & (jnp.arange(n) != cur_row)
-        u = jnp.where(others, u + min_val - spc[jnp.clip(col4row, 0, n - 1)], u)
+        u = jnp.where(idx == cur_row, u + min_val, u)
+        others = sr & (idx != cur_row)
+        col_of_row = idx[:, None] == jnp.clip(col4row, 0, n - 1)  # [col, row]
+        u = jnp.where(others, u + min_val - _pick(spc[:, None], col_of_row),
+                      u)
         v = jnp.where(sc, v + spc - min_val, v)
 
         # --- augment along the alternating path back from sink ---
@@ -126,10 +144,10 @@ def solve(cost: jnp.ndarray) -> jnp.ndarray:
 
         def aug_body(st):
             col4row, row4col, j, _done = st
-            i = path[j]
-            row4col = row4col.at[j].set(i)
-            nxt = col4row[i]
-            col4row = col4row.at[i].set(j)
+            i = _pick(path, idx == j)
+            row4col = jnp.where(idx == j, i, row4col)
+            nxt = _pick(col4row, idx == i)
+            col4row = jnp.where(idx == i, j, col4row)
             return col4row, row4col, nxt, i == cur_row
 
         col4row, row4col, _, _ = lax.while_loop(

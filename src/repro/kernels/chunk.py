@@ -30,14 +30,16 @@ The body is ``ref.step_chunk_lane`` — the exact serving step (masked lane
 re-init + fused frame + lifecycle + emit) in kernel-safe vector algebra —
 so the megakernel is bit-identical to F per-frame dispatches.
 
-VMEM per grid step at T=D=16, block_s=128: the resident state is ~994
-words/lane (x 7x16 + p 49x16 + 6 int slot fields + 2 counters) = ~0.5 MiB
-per copy, ~1 MiB with the input seed; per-frame slabs (det+masks+t2d in,
-boxes+ids out) are ~113 KiB live x2 for double-buffering, and the largest
-intermediate (the [D, T, block_s] IoU) is 128 KiB.  Total < 2 MiB —
-crucially **independent of chunk size F**: frames stream through the minor
-grid axis, so only HBM staging grows with F (~100 KiB/frame).  That is why
-the chunk can be arbitrarily long without revisiting the §2.3 budget.
+VMEM per grid step at T=D=16, block_s=128: the resident state is ~1,010
+words/lane (x 7x16 + p 49x16 + 7 int slot fields + 2 counters) = ~0.5 MiB
+per copy, with the input seed and double-buffering a few copies; the
+per-frame slabs (det+masks+t2d in, boxes+ids out) are ~113 KiB each.  The
+TPU compiler accepts the kernel with a scoped VMEM limit of 3.6 MiB
+(``trk_to_det``), 3.9 MiB (greedy) and 4.0 MiB (3 classes + 8-d embedding)
+for v5e, against the 16 MiB default — and the budget is **independent of
+chunk size F**: frames stream through the minor grid axis, so only HBM
+staging grows with F (~100 KiB/frame).  That is why the chunk can be
+arbitrarily long without revisiting the §2.3 budget.
 
 Association (DESIGN.md §6): greedy runs fully in-kernel (masked argmax
 rounds are vector algebra).  The Hungarian path keeps PR 3's split,

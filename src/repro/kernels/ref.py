@@ -244,11 +244,12 @@ def frame_lane(x: jnp.ndarray, p: jnp.ndarray, det: jnp.ndarray,
     x, p = predict_lane(x, p)                               # [7,T,S], [49,T,S]
     if trk_to_det is not None:
         # precomputed assignment (already gated): a matching, so matched
-        # detections are exactly the assigned values >= 0
-        d = det.shape[0]
-        di_iota = jnp.arange(d, dtype=jnp.int32).reshape(
-            (d, 1) + (1,) * (trk_to_det.ndim - 1))
-        matched_det = (trk_to_det[None] == di_iota).any(axis=1)
+        # detections are exactly the assigned values >= 0.  One int32 row
+        # per detection, reduced over the slot axis: Mosaic lays out no
+        # reshape, stack or cross-sublane reduction of a bool array.
+        rows = [jnp.max((trk_to_det == di).astype(jnp.int32), axis=0,
+                        keepdims=True) for di in range(det.shape[0])]
+        matched_det = jnp.concatenate(rows, axis=0) > 0
     else:
         trk_boxes = z_to_xyxy_lane(x[:4])                   # [T, 4, S]
         iou = iou_lane(det, trk_boxes)                      # [D, T, S]
@@ -349,33 +350,24 @@ def assign_slots_lane_unrolled(free_mask: jnp.ndarray,
                                want_mask: jnp.ndarray) -> jnp.ndarray:
     """Kernel-safe ``slots.assign_slots_lane``: the same rank matching
     (the k-th claimant takes the k-th free slot, -1 when the pool is
-    exhausted) computed with trace-time-unrolled compare/accumulate
-    instead of cumsum + scatter + ``take_along_axis``, which don't lower
-    inside a Pallas TPU kernel body.  ``free [T, ...]`` bool,
-    ``want [D, ...]`` bool -> ``slot_for [D, ...] int32``; integer-exact
-    vs the scatter version (``tests/test_lane.py`` locks the equivalence).
+    exhausted) as a trace-time-unrolled pass over the claimants, each
+    taking the lowest-index slot still free — instead of cumsum +
+    scatter + ``take_along_axis``, which don't lower inside a Pallas TPU
+    kernel body.  ``free [T, ...]`` bool, ``want [D, ...]`` bool ->
+    ``slot_for [D, ...] int32``; integer-exact vs the scatter version
+    (``tests/test_lane.py`` locks the equivalence).
     """
-    t, d = free_mask.shape[0], want_mask.shape[0]
-    zero = jnp.zeros(free_mask.shape[1:], jnp.int32)
-    free_rank = []                    # free slots with index < ti
-    num_free = zero
-    for ti in range(t):
-        free_rank.append(num_free)
-        num_free = num_free + free_mask[ti].astype(jnp.int32)
-    want_rank = []                    # claimants with index < di
-    acc = zero
-    for di in range(d):
-        want_rank.append(acc)
-        acc = acc + want_mask[di].astype(jnp.int32)
+    t = free_mask.shape[0]
+    ti_iota = jax.lax.broadcasted_iota(jnp.int32, free_mask.shape, 0)
+    free = free_mask
     rows = []
-    for di in range(d):
-        ok = want_mask[di] & (want_rank[di] < num_free)
-        slot = jnp.full(free_mask.shape[1:], -1, jnp.int32)
-        for ti in range(t):
-            hit = free_mask[ti] & (free_rank[ti] == want_rank[di])
-            slot = jnp.where(ok & hit, ti, slot)
+    for di in range(want_mask.shape[0]):
+        first = jnp.min(jnp.where(free, ti_iota, t), axis=0,
+                        keepdims=True)                       # [1, ...]
+        slot = jnp.where(want_mask[di:di + 1] & (first < t), first, -1)
+        free = free & (ti_iota != slot)
         rows.append(slot)
-    return jnp.stack(rows, axis=0)
+    return jnp.concatenate(rows, axis=0)
 
 
 def step_chunk_lane(state: ChunkState, det: jnp.ndarray,
@@ -415,8 +407,12 @@ def step_chunk_lane(state: ChunkState, det: jnp.ndarray,
     dt = state.x.dtype
     t = state.alive.shape[0]
     d = det.shape[0]
-    act = active[0] > 0                                      # [S]
-    rst = reset[0] > 0                                       # [S]
+    # per-lane flags stay [1, S] and per-detection rows are unit-row
+    # slices: Mosaic lays out no bool array that is reshaped, stacked or
+    # cut down to a 1-D row, so masks are compared only where a select
+    # consumes them
+    act = active > 0                                         # [1, S]
+    rst = reset > 0                                          # [1, S]
 
     # masked lane re-init (reset_lanes semantics, uid_start=1): a recycled
     # lane and its admitted sequence's first frame share the step.  The
@@ -425,27 +421,26 @@ def step_chunk_lane(state: ChunkState, det: jnp.ndarray,
     # scalar path is bit-identical (every entry is exactly representable).
     p0 = tuple(float(v) for v in
                kalman.initial_covariance_np().astype(dt).reshape(49))
-    x = jnp.where(rst[None, None], jnp.zeros((), dt), state.x)
-    p = jnp.stack([jnp.where(rst[None], v, state.p[i])
+    x = jnp.where(rst[None], jnp.zeros((), dt), state.x)
+    p = jnp.stack([jnp.where(rst, v, state.p[i])
                    for i, v in enumerate(p0)], axis=0)
     e = state.embed.shape[0]
     emb = state.embed
     if e > 0:
-        emb = jnp.where(rst[None, None], jnp.zeros((), dt), emb)
+        emb = jnp.where(rst[None], jnp.zeros((), dt), emb)
     zero = jnp.zeros((), jnp.int32)
-    alive0 = (state.alive > 0) & ~rst[None]
+    alive0 = (state.alive > 0) & ~rst
     pool0 = slots.SlotPool(
         alive=alive0,
-        age=jnp.where(rst[None], zero, state.age),
-        hits=jnp.where(rst[None], zero, state.hits),
-        hit_streak=jnp.where(rst[None], zero, state.hit_streak),
-        time_since_update=jnp.where(rst[None], zero,
-                                    state.time_since_update),
-        uid=jnp.where(rst[None], -1, state.uid),
-        cls=jnp.where(rst[None], -1, state.cls),
-        next_uid=jnp.where(rst, 1, state.next_uid[0]),       # [S]
+        age=jnp.where(rst, zero, state.age),
+        hits=jnp.where(rst, zero, state.hits),
+        hit_streak=jnp.where(rst, zero, state.hit_streak),
+        time_since_update=jnp.where(rst, zero, state.time_since_update),
+        uid=jnp.where(rst, -1, state.uid),
+        cls=jnp.where(rst, -1, state.cls),
+        next_uid=jnp.where(rst, 1, state.next_uid),          # [1, S]
     )
-    fc0 = jnp.where(rst, zero, state.frame_count[0])         # [S]
+    fc0 = jnp.where(rst, zero, state.frame_count)            # [1, S]
 
     # 1-3. fused predict + IoU + assign + masked update — the same body
     # the per-frame kernel runs (inactive lanes restored inside)
@@ -460,48 +455,40 @@ def step_chunk_lane(state: ChunkState, det: jnp.ndarray,
     pool = slots.tick(pool0, t2d >= 0, max_age)
 
     # 4b. births from unmatched detections into free slots (kernel-safe
-    # rank matching + unrolled one-hot scatter over the T x D grid)
-    unmatched = (det_mask > 0) & ~matched & act[None]
+    # rank matching + an unrolled one-hot select per detection over the
+    # [T, S] slot grid; claimed slots are distinct, so at most one
+    # detection selects each slot and the order of the selects is moot)
+    unmatched = (det_mask > 0) & ~matched & act
     slot_for = assign_slots_lane_unrolled(~pool.alive, unmatched)
     z_det = xyxy_to_z_lane(det)                              # [4, D, S]
-    claimed = slot_for >= 0
-    born_order = []                                          # claimants < di
-    n_born = jnp.zeros(slot_for.shape[1:], jnp.int32)
+    ti_iota = jax.lax.broadcasted_iota(jnp.int32, pool.uid.shape, 0)
+    n_born = jnp.zeros_like(pool.next_uid)                   # claimants < di
+    born = jnp.zeros_like(pool.uid)                          # [T, S] 0/1
+    uid, cls = pool.uid, pool.cls
+    zb = jnp.zeros((4,) + pool.uid.shape, dt)
     for di in range(d):
-        born_order.append(n_born)
-        n_born = n_born + claimed[di].astype(jnp.int32)
-    born_rows, uid_rows, cls_rows, zb_rows = [], [], [], []
-    for ti in range(t):
-        sel_any = jnp.zeros(slot_for.shape[1:], bool)
-        uid_t = pool.uid[ti]
-        cls_t = pool.cls[ti]
-        zb_t = jnp.zeros((4,) + slot_for.shape[1:], dt)
-        for di in range(d):
-            sel = slot_for[di] == ti      # claimed slots are distinct
-            sel_any = sel_any | sel
-            uid_t = jnp.where(sel, pool.next_uid + born_order[di], uid_t)
-            cls_t = jnp.where(
-                sel, zero if det_class is None else det_class[di], cls_t)
-            zb_t = jnp.where(sel[None], z_det[:, di], zb_t)
-        born_rows.append(sel_any)
-        uid_rows.append(uid_t)
-        cls_rows.append(cls_t)
-        zb_rows.append(zb_t)
-    born = jnp.stack(born_rows, axis=0)                      # [T, S]
-    zb = jnp.stack(zb_rows, axis=1)                          # [4, T, S]
+        slot_di = slot_for[di:di + 1]                        # [1, S]
+        sel = slot_di == ti_iota                             # [T, S]
+        born = jnp.where(sel, 1, born)
+        uid = jnp.where(sel, pool.next_uid + n_born, uid)
+        cls = jnp.where(sel, zero if det_class is None
+                        else det_class[di:di + 1], cls)
+        zb = jnp.where(sel[None], z_det[:, di:di + 1], zb)
+        n_born = n_born + (slot_di >= 0).astype(jnp.int32)
+    is_born = born > 0
     pool = slots.SlotPool(
-        alive=pool.alive | born,
-        age=jnp.where(born, zero, pool.age),
-        hits=jnp.where(born, zero, pool.hits),
-        hit_streak=jnp.where(born, zero, pool.hit_streak),
-        time_since_update=jnp.where(born, zero, pool.time_since_update),
-        uid=jnp.stack(uid_rows, axis=0),
-        cls=jnp.stack(cls_rows, axis=0),
+        alive=pool.alive | is_born,
+        age=jnp.where(is_born, zero, pool.age),
+        hits=jnp.where(is_born, zero, pool.hits),
+        hit_streak=jnp.where(is_born, zero, pool.hit_streak),
+        time_since_update=jnp.where(is_born, zero, pool.time_since_update),
+        uid=uid,
+        cls=cls,
         next_uid=pool.next_uid + n_born,
     )
     x_init = jnp.concatenate([zb, jnp.zeros((3,) + zb.shape[1:], dt)], 0)
-    x = jnp.where(born[None], x_init, x)
-    p = jnp.stack([jnp.where(born, v, p[i]) for i, v in enumerate(p0)],
+    x = jnp.where(is_born[None], x_init, x)
+    p = jnp.stack([jnp.where(is_born, v, p[i]) for i, v in enumerate(p0)],
                   axis=0)
 
     # embedding refresh: matched tracks take their matched detection's
@@ -509,42 +496,32 @@ def step_chunk_lane(state: ChunkState, det: jnp.ndarray,
     # same unrolled per-detection loop order as the per-frame engine path
     # (`SortEngine.lane_step`), for chunk-vs-frame bit parity.
     if e > 0 and det_embed is not None:
-        ti_iota = jnp.arange(t, dtype=jnp.int32)[:, None]    # [T, 1]
         for di in range(d):
             m_sel = (t2d == di)[None]                        # [1, T, S]
             emb = jnp.where(m_sel, det_embed[di][:, None], emb)
         for di in range(d):
-            b_sel = (slot_for[di][None, :] == ti_iota)[None]  # [1, T, S]
+            b_sel = (slot_for[di:di + 1] == ti_iota)[None]   # [1, T, S]
             emb = jnp.where(b_sel, det_embed[di][:, None], emb)
 
     # inactive lanes: lifecycle freezes (x/p were restored inside
     # frame_lane; births can't fire — `unmatched` was gated by act)
     def sel(new, old):
-        return jnp.where(act[None], new, old)
+        if new.dtype == jnp.bool_:    # Mosaic selects no bool operands
+            return (act & new) | (~act & old)
+        return jnp.where(act, new, old)
 
-    pool = slots.SlotPool(
-        alive=sel(pool.alive, pool0.alive),
-        age=sel(pool.age, pool0.age),
-        hits=sel(pool.hits, pool0.hits),
-        hit_streak=sel(pool.hit_streak, pool0.hit_streak),
-        time_since_update=sel(pool.time_since_update,
-                              pool0.time_since_update),
-        uid=sel(pool.uid, pool0.uid),
-        cls=sel(pool.cls, pool0.cls),
-        next_uid=jnp.where(act, pool.next_uid, pool0.next_uid),
-    )
-    fc = fc0 + act.astype(jnp.int32)                         # [S]
+    pool = slots.SlotPool(*(sel(new, old) for new, old in zip(pool, pool0)))
+    fc = fc0 + act.astype(jnp.int32)                         # [1, S]
 
     # 5. emit: updated this frame AND (probation passed OR warmup)
-    warmup = (fc <= min_hits)[None]                          # [1, S]
+    warmup = fc <= min_hits                                  # [1, S]
     emit = (pool.alive & (pool.time_since_update < 1)
-            & ((pool.hit_streak >= min_hits) | warmup) & act[None])
+            & ((pool.hit_streak >= min_hits) | warmup) & act)
     new_state = ChunkState(
         x=x, p=p, alive=pool.alive.astype(jnp.int32), age=pool.age,
         hits=pool.hits, hit_streak=pool.hit_streak,
         time_since_update=pool.time_since_update, uid=pool.uid,
-        cls=pool.cls,
-        next_uid=pool.next_uid[None, :], frame_count=fc[None, :],
+        cls=pool.cls, next_uid=pool.next_uid, frame_count=fc,
         embed=emb)
     outs = ChunkOuts(boxes=z_to_xyxy_lane(x[:4]), uid=pool.uid, emit=emit,
                      trk_to_det=t2d, matched_det=matched, cls=pool.cls)

@@ -11,16 +11,10 @@ Smaller test meshes come from :func:`make_mesh`.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 explicit-sharding API; older jax has implicit-auto only
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -185,8 +185,7 @@ def moe_ffn_ep(p, x, cfg: ModelConfig, mesh, dp_axes: tuple, tp_axis: str,
 
     spec_x = P(dp_axes if len(dp_axes) > 1 else dp_axes[0], None, None)
     w_spec = P(tp_axis, fsdp_axis if gather_w else None, None)
-    from repro.compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(spec_x, P(None, None), w_spec, w_spec, w_spec),
         out_specs=spec_x,

@@ -11,7 +11,7 @@ lane shard.
 
 Because sequences are independent — no phase of the frame step ever
 crosses lanes (DESIGN.md §3.2) — the sharded program needs **zero
-cross-device collectives**: ``shard_map`` (via :mod:`repro.compat`)
+cross-device collectives**: ``jax.shard_map``
 partitions the state and chunk operands, every device scans its shard
 locally, and a sharded run is *bit-identical* to the single-device run
 (``tests/test_device_sharding.py`` locks this down for both engine paths
@@ -43,7 +43,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import kalman, slots
 from repro.core.sort import (LaneSortState, SortOutput, SortState,
                              lane_state_of, resize_streams, sort_state_of)
@@ -228,8 +227,8 @@ class LaneSharding:
                      SortOutput(boxes=_chunk_spec(4), uid=_chunk_spec(3),
                                 emit=_chunk_spec(3), matched_det=_chunk_spec(3),
                                 cls=_chunk_spec(3)))
-        return compat.shard_map(
-            local_chunk, self.mesh,
+        return jax.shard_map(
+            local_chunk, mesh=self.mesh,
             in_specs=(self._state_specs, _chunk_spec(4), _chunk_spec(3),
                       _chunk_spec(2), _chunk_spec(2))
                      + tuple(_chunk_spec(n) for n in extra_operand_ndims),

@@ -97,8 +97,7 @@ def make_train_step(model, par, opt_cfg: AdamWConfig,
             loss = jax.lax.psum(loss, "pod") / npods
             return apply(st, loss, grads)
 
-        from repro.compat import shard_map
-        return shard_map(
+        return jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(P(), P("pod")),   # state replicated over pod; batch split
