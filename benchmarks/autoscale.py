@@ -92,8 +92,7 @@ def _mean_width(sched) -> float:
 
 def run(light: int = 2, heavy: int = 12, frames: int = 60,
         min_lanes: int = 2, max_lanes: int = 8, chunk: int = 8,
-        seed: int = 0, repeats: int = 2, use_kernels: bool = True,
-        json_dir: str | None = None):
+        seed: int = 0, repeats: int = 2, use_kernels: bool = True):
     if repeats < 1:
         raise ValueError(f"repeats must be >= 1 (rep 0 only warms the "
                          f"jit and is never timed), got {repeats}")
@@ -145,17 +144,9 @@ def run(light: int = 2, heavy: int = 12, frames: int = 60,
         ("autoscale/elastic_vs_fixed_max", u_el / max(u_max, 1e-9),
          "lane-utilization ratio (elastic right-sizes the quiet phases)"),
     ]
-    if json_dir is not None:
-        from benchmarks._record import write_bench
-        write_bench("autoscale",
-                    dict(light=light, heavy=heavy, frames=frames,
-                         min_lanes=min_lanes, max_lanes=max_lanes,
-                         chunk=chunk, seed=seed, repeats=repeats,
-                         use_kernels=use_kernels),
-                    rows, json_dir)
     return rows
 
 
 if __name__ == "__main__":
-    for name, value, derived in run(json_dir="."):
+    for name, value, derived in run():
         print(f"{name},{value:.4f},{derived}")

@@ -63,7 +63,7 @@ def _traffic(num_seqs: int, long_frames: int, skew: int, seed: int):
 def run(num_seqs: int = 16, long_frames: int = 96, skew: int = 4,
         num_lanes: int = 8, chunk: int = 16, seed: int = 0,
         repeats: int = 2, use_kernels: bool = True,
-        device_counts: tuple = (1, 2, 4, 8), json_dir: str | None = None):
+        device_counts: tuple = (1, 2, 4, 8)):
     if repeats < 1:
         raise ValueError(f"repeats must be >= 1, got {repeats}")
     # jax deferred so the __main__ block can force host devices first
@@ -116,17 +116,6 @@ def run(num_seqs: int = 16, long_frames: int = 96, skew: int = 4,
                      f"jax.device_count()={avail}, num_lanes={num_lanes} "
                      f"(set XLA_FLAGS=--xla_force_host_platform_device_"
                      f"count={max(device_counts)} before jax initializes)"))
-    if json_dir is not None:
-        from benchmarks._record import write_bench
-        write_bench("device_scaling",
-                    dict(num_seqs=num_seqs, long_frames=long_frames,
-                         skew=skew, num_lanes=num_lanes, chunk=chunk,
-                         seed=seed, repeats=repeats,
-                         use_kernels=use_kernels,
-                         device_counts=list(device_counts),
-                         measured_counts=counts,
-                         backend=jax.default_backend()),
-                    rows, json_dir)
     return rows
 
 
@@ -137,5 +126,5 @@ if __name__ == "__main__":
     if "jax" not in sys.modules:
         os.environ.setdefault(
             "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-    for name, value, derived in run(json_dir="."):
+    for name, value, derived in run():
         print(f"{name},{value:.4f},{derived}")

@@ -87,7 +87,7 @@ def _planned_chunk(num_frames: int, num_lanes: int, seed: int):
 
 
 def run(chunk_sizes=CHUNK_SIZES, num_lanes: int = 4, seed: int = 0,
-        repeats: int = 3, json_dir: str | None = None):
+        repeats: int = 3):
     def engine(chunk_kernel: bool, d: int) -> SortEngine:
         return SortEngine(SortConfig(max_trackers=8, max_detections=d,
                                      use_kernels=True, assoc="greedy",
@@ -122,17 +122,10 @@ def run(chunk_sizes=CHUNK_SIZES, num_lanes: int = 4, seed: int = 0,
                      f"dispatch_ratio={counts['scan'] / counts['megakernel']:.0f}x"
                      + note))
 
-    if json_dir is not None:
-        from benchmarks._record import write_bench
-        write_bench("dispatch_overhead",
-                    dict(chunk_sizes=list(chunk_sizes), num_lanes=num_lanes,
-                         seed=seed, repeats=repeats,
-                         backend=jax.default_backend()),
-                    rows, json_dir)
     return rows
 
 
 if __name__ == "__main__":
     print("name,us_per_call,derived")
-    for row_name, value, derived in run(json_dir="."):
+    for row_name, value, derived in run():
         print(f"{row_name},{value:.4f},{derived}")

@@ -10,10 +10,8 @@ same synthetic scene geometry throughout.  The derived column carries the
 per-run emitted-track count so a cost/partition change that silently
 alters tracking behaviour shows up next to its latency.
 
-Run via ``benchmarks.run`` (section ``multiclass``) or standalone;
-``--json`` / ``json_dir`` writes ``BENCH_multiclass.json``
-(``benchmarks/_record.py`` schema).  CI smokes it with a small
-``num_frames`` so the multi-class rows cannot rot.
+Run via ``benchmarks.run`` (section ``multiclass``) or standalone.  CI
+smokes it with a small ``num_frames`` so the multi-class rows cannot rot.
 """
 from __future__ import annotations
 
@@ -39,7 +37,7 @@ CONFIGS = (
 )
 
 
-def run(seed: int = 0, num_frames: int = 150, json_dir: str | None = None):
+def run(seed: int = 0, num_frames: int = 150):
     scene = SceneConfig(num_frames=num_frames, max_objects=10,
                         miss_rate=0.05, fp_rate=0.2, det_noise=2.0,
                         seed=seed)
@@ -76,23 +74,13 @@ def run(seed: int = 0, num_frames: int = 150, json_dir: str | None = None):
                      f"x{us / base_us:.2f} vs 1-class iou, "
                      f"emitted={emitted}, one lane-batched solve "
                      f"(block-diagonal via feasibility mask)"))
-    if json_dir is not None:
-        from benchmarks._record import write_bench
-        write_bench("multiclass",
-                    dict(seed=seed, num_frames=num_frames,
-                         max_detections=d, embed_dim=EMBED_DIM,
-                         configs=[f"{t}" for t, _, _ in CONFIGS]),
-                    rows, json_dir)
     return rows
 
 
 if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
-    ap.add_argument("--json", nargs="?", const=".", default=None,
-                    metavar="DIR")
     ap.add_argument("--frames", type=int, default=150)
     args = ap.parse_args()
-    for name, value, derived in run(num_frames=args.frames,
-                                    json_dir=args.json):
+    for name, value, derived in run(num_frames=args.frames):
         print(f"{name},{value:.4f},{derived}")

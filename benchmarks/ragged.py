@@ -72,8 +72,7 @@ def _run_padmax(run_fn, eng, seqs, num_lanes: int, f_max: int, d: int) -> int:
 
 def run(num_seqs: int = 16, long_frames: int = 120, skew: int = 4,
         num_lanes: int = 4, chunk: int = 32, seed: int = 0,
-        repeats: int = 3, use_kernels: bool = True,
-        json_dir: str | None = None):
+        repeats: int = 3, use_kernels: bool = True):
     seqs, d = _pad_dets(_mix(num_seqs, long_frames, skew, seed))
     f_max = max(s[1].shape[0] for s in seqs)
     real_frames = sum(s[1].shape[0] for s in seqs)
@@ -123,18 +122,9 @@ def run(num_seqs: int = 16, long_frames: int = 120, skew: int = 4,
          f"{skew}:1 length skew, {num_seqs} seqs, "
          f"{'fused' if use_kernels else 'per-phase'} path"),
     ]
-    if json_dir is not None:
-        from benchmarks._record import write_bench
-        write_bench("ragged",
-                    dict(num_seqs=num_seqs, long_frames=long_frames,
-                         skew=skew, num_lanes=num_lanes, chunk=chunk,
-                         seed=seed, repeats=repeats,
-                         use_kernels=use_kernels,
-                         backend=jax.default_backend()),
-                    rows, json_dir)
     return rows
 
 
 if __name__ == "__main__":
-    for name, value, derived in run(json_dir="."):
+    for name, value, derived in run():
         print(f"{name},{value:.4f},{derived}")

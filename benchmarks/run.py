@@ -7,10 +7,6 @@ Prints ``name,us_per_call,derived`` CSV.  Table mapping:
 * Table V   -> benchmarks.speedup    (per-op Python vs fused batched JAX)
 * Table VI  -> benchmarks.scaling    (strong vs weak vs throughput)
 
-``--json [DIR]`` additionally writes ``BENCH_<name>.json`` artifacts
-(schema in ``benchmarks/_record.py``) for the sections that support
-them: speedup, ragged, autoscale, device_scaling, dispatch_overhead.
-
 Roofline (§Roofline, from the dry-run) lives in ``benchmarks.roofline`` —
 run it separately after ``repro.launch.dryrun``.
 """
@@ -27,50 +23,43 @@ def main(argv=None) -> None:
                             multiclass, ragged, scaling, service_soak,
                             speedup)
 
-    ap = argparse.ArgumentParser(
+    argparse.ArgumentParser(
         prog="benchmarks.run",
-        description="Run every benchmark section; prints CSV to stdout.")
-    ap.add_argument(
-        "--json", nargs="?", const=".", default=None, metavar="DIR",
-        help="also write BENCH_<name>.json artifacts to DIR (default: cwd) "
-             "for the sections that support them")
-    args = ap.parse_args(argv)
+        description="Run every benchmark section; prints CSV to stdout."
+    ).parse_args(argv)
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
 
-    # (section, run_fn, emits BENCH_<name>.json under --json)
     sections = [
-        ("tableI", datasets.run, False),
-        ("tableIV", kernel_ai.run, False),
-        ("tableV", speedup.run, True),
-        ("tableVI", scaling.run, False),
-        ("ragged", ragged.run, True),
-        ("ablation", association_ablation.run, False),
+        ("tableI", datasets.run),
+        ("tableIV", kernel_ai.run),
+        ("tableV", speedup.run),
+        ("tableVI", scaling.run),
+        ("ragged", ragged.run),
+        ("ablation", association_ablation.run),
         # elastic vs fixed lane budgets on a bursty 4-phase arrival trace
         # (DESIGN.md §8)
-        ("autoscale", autoscale.run, True),
+        ("autoscale", autoscale.run),
         # reports per-device rows only up to jax.device_count(); export
         # XLA_FLAGS=--xla_force_host_platform_device_count=8 for the full
         # {1,2,4,8} sweep on CPU (DESIGN.md §7)
-        ("devices", device_scaling.run, True),
+        ("devices", device_scaling.run),
         # per-frame scan vs chunk-resident megakernel dispatch accounting
         # (DESIGN.md §9)
-        ("dispatch", dispatch_overhead.run, True),
+        ("dispatch", dispatch_overhead.run),
         # composed costs x class partition vs the single-class IoU
         # baseline — one block-diagonal lane-batched solve (DESIGN.md §10)
-        ("multiclass", multiclass.run, True),
+        ("multiclass", multiclass.run),
         # TrackingService front-end: admission/delivery overhead,
         # chunk-boundary checkpoint tax, resume latency, shed behaviour
         # (DESIGN.md §11)
-        ("service", service_soak.run, True),
+        ("service", service_soak.run),
     ]
     print("name,us_per_call,derived")
     failed = 0
-    for name, fn, emits_json in sections:
-        kwargs = ({"json_dir": args.json}
-                  if (args.json is not None and emits_json) else {})
+    for name, fn in sections:
         try:
-            for row_name, value, derived in fn(**kwargs):
+            for row_name, value, derived in fn():
                 print(f"{row_name},{value:.4f},{derived}")
                 sys.stdout.flush()
         except Exception:

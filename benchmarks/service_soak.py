@@ -59,8 +59,7 @@ async def _serve_all(svc, seqs) -> float:
 
 
 def run(num_seqs: int = 8, frames: int = 60, num_lanes: int = 4,
-        chunk: int = 16, seed: int = 0, use_kernels: bool = False,
-        json_dir: str | None = None):
+        chunk: int = 16, seed: int = 0, use_kernels: bool = False):
     seqs, d = _sequences(num_seqs, frames, seed)
     real_frames = num_seqs * frames
     eng = SortEngine(SortConfig(max_trackers=16, max_detections=d,
@@ -154,16 +153,9 @@ def run(num_seqs: int = 8, frames: int = 60, num_lanes: int = 4,
          f"over-rate burst: {shed}/{num_seqs} shed, mean "
          f"retry_after={np.mean(hints):.2f}s, peak pending={peak}"),
     ]
-    if json_dir is not None:
-        from benchmarks._record import write_bench
-        write_bench("service",
-                    dict(num_seqs=num_seqs, frames=frames,
-                         num_lanes=num_lanes, chunk=chunk, seed=seed,
-                         use_kernels=use_kernels),
-                    rows, json_dir)
     return rows
 
 
 if __name__ == "__main__":
-    for name, value, derived in run(json_dir="."):
+    for name, value, derived in run():
         print(f"{name},{value:.4f},{derived}")

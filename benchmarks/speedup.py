@@ -28,7 +28,7 @@ from repro.data.synthetic import SceneConfig, generate_scene
 
 
 def run(num_streams: int = 64, num_frames: int = 120, seed: int = 0,
-        repeats: int = 3, json_dir: str | None = None):
+        repeats: int = 3):
     scenes = [generate_scene(SceneConfig(num_frames=num_frames,
                                          max_objects=10, seed=seed + i))
               for i in range(num_streams)]
@@ -92,11 +92,4 @@ def run(num_streams: int = 64, num_frames: int = 120, seed: int = 0,
         ("tableV/jax_fused_lane_fps", 1.0 / t_fused,
          f"streams={num_streams}"),
     ]
-    if json_dir is not None:
-        from benchmarks._record import write_bench
-        write_bench("speedup",
-                    dict(num_streams=num_streams, num_frames=num_frames,
-                         seed=seed, repeats=repeats,
-                         backend=jax.default_backend()),
-                    rows, json_dir)
     return rows

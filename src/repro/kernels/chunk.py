@@ -215,6 +215,7 @@ def fused_chunk(state, det, det_mask, active, reset, trk_to_det=None,
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
+        name="fused_chunk",           # the op name traces are matched on
     )(*operands)
     out_state_leaves = list(results[:n_state])
     if not has_embed:

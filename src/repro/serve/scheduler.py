@@ -56,6 +56,12 @@ from repro.core import slots
 from repro.core.sort import SortEngine, lane_state_of, sort_state_of
 from repro.data.stream import ReorderBuffer, SequenceTracks
 
+# Profiler spans (``jax.profiler.TraceAnnotation``) of one dispatched
+# chunk, in the order it runs them; each carries the stat ``chunk=<n>``,
+# the chunk's number (``chunks_run`` before it).
+SPANS = ("sched.plan", "sched.stage", "sched.fetch", "sched.unpack",
+         "sched.release")
+
 
 def lane_ladder(min_lanes: int, max_lanes: int) -> tuple[int, ...]:
     """The pre-compiled width ladder (DESIGN.md §8): power-of-two
@@ -215,6 +221,8 @@ class StreamScheduler:
         # construction width (elastic mode resizes between chunks).
         self.lane_steps = 0
         self.chunks_run = 0
+        self.bytes_staged = 0          # chunk operands, host -> device
+        self.bytes_fetched = 0         # chunk outputs + next_uid, back
         self.admissions: list[tuple[int, int]] = []  # (seq index, step)
         self.resizes: list[tuple[int, int, int]] = []  # (chunk, old, new)
         # one entry (the traced lane width; per-shard width in mesh mode)
@@ -521,38 +529,50 @@ class StreamScheduler:
         if not self._has_step_work:
             # nothing to dispatch — only buffered completions to release
             return self._ready.pop_ready()
-        self._maybe_resize()
-        det, dm, active, reset, extras, mapping = self._plan_chunk()
-        if self._sharding is not None:
-            operands = self._sharding.place(det, dm, active, reset, *extras)
-        else:
-            operands = tuple(jnp.asarray(a)
-                             for a in (det, dm, active, reset) + extras)
-        self._state, outs = self._chunk_fn(self._state, *operands)
-        self._check_uid_headroom()
-        boxes = np.asarray(outs.boxes)                # [C, L, T, 4]
-        uid = np.asarray(outs.uid)
-        emit = np.asarray(outs.emit)
-        cls = np.asarray(outs.cls) if self._need_class else None
-        finished = []
-        for t, lane, seq, k in mapping:
-            # copies, so buffering a row doesn't pin the whole chunk array
-            # until a long-running neighbour sequence finalizes
-            seq.boxes.append(boxes[t, lane].copy())
-            seq.uid.append(uid[t, lane].copy())
-            seq.emit.append(emit[t, lane].copy())
-            if cls is not None:
-                seq.cls.append(cls[t, lane].copy())
-            if k + 1 == seq.length:
-                finished.append(seq)
-        self.frames_processed += len(mapping)
-        # denominator from the planned schedule, not the raw chunk size:
-        # fully-idle tail steps of a draining chunk carry no lanes' work
-        self.lane_steps += int(active.any(axis=1).sum()) * self.num_lanes
-        self.chunks_run += 1
-        for seq in finished:
-            self._finalize(seq)
-        return self._ready.pop_ready()
+        n = self.chunks_run
+        span = jax.profiler.TraceAnnotation
+        with span("sched.plan", chunk=n):
+            self._maybe_resize()
+            det, dm, active, reset, extras, mapping = self._plan_chunk()
+        staged = (det, dm, active, reset) + extras
+        with span("sched.stage", chunk=n):
+            if self._sharding is not None:
+                operands = self._sharding.place(*staged)
+            else:
+                operands = tuple(jnp.asarray(a) for a in staged)
+            self._state, outs = self._chunk_fn(self._state, *operands)
+        with span("sched.fetch", chunk=n):
+            next_uid = self._check_uid_headroom()
+            boxes = np.asarray(outs.boxes)            # [C, L, T, 4]
+            uid = np.asarray(outs.uid)
+            emit = np.asarray(outs.emit)
+            cls = np.asarray(outs.cls) if self._need_class else None
+        with span("sched.unpack", chunk=n):
+            finished = []
+            for t, lane, seq, k in mapping:
+                # copies, so buffering a row doesn't pin the whole chunk
+                # array until a long-running neighbour sequence finalizes
+                seq.boxes.append(boxes[t, lane].copy())
+                seq.uid.append(uid[t, lane].copy())
+                seq.emit.append(emit[t, lane].copy())
+                if cls is not None:
+                    seq.cls.append(cls[t, lane].copy())
+                if k + 1 == seq.length:
+                    finished.append(seq)
+            self.frames_processed += len(mapping)
+            # denominator from the planned schedule, not the raw chunk
+            # size: fully-idle tail steps of a draining chunk carry no
+            # lanes' work
+            self.lane_steps += int(active.any(axis=1).sum()) * self.num_lanes
+            self.chunks_run += 1
+            self.bytes_staged += sum(a.nbytes for a in staged)
+            self.bytes_fetched += sum(a.nbytes for a in
+                                      (next_uid, boxes, uid, emit, cls)
+                                      if a is not None)
+        with span("sched.release", chunk=n):
+            for seq in finished:
+                self._finalize(seq)
+            return self._ready.pop_ready()
 
     def _finalize(self, seq: _Seq) -> None:
         t = self.engine.config.max_trackers
@@ -568,8 +588,13 @@ class StreamScheduler:
                   else np.zeros((0, t), np.int32))
                  if self._need_class else None),
         ))
+        # free the per-frame rows now that they are stacked: a full-lane
+        # chunk finishes ~65k rows, and freeing them is release work (on a
+        # v5e host ~10% of a chunk), not work of whatever drops the seq
+        for rows in (seq.boxes, seq.uid, seq.emit, seq.cls):
+            rows.clear()
 
-    def _check_uid_headroom(self) -> None:
+    def _check_uid_headroom(self) -> np.ndarray:
         """Guard the per-lane int32 uid counter (``SlotPool.next_uid``).
 
         ``reset_ragged`` resets the counter to ``uid_start`` on every lane
@@ -580,7 +605,7 @@ class StreamScheduler:
         check fetches the ``[L]`` int32 counter row each chunk (a tiny
         cross-device gather in mesh mode) — negligible next to the chunk's
         own output transfer, and the chunk boundary is already a host
-        sync point.
+        sync point.  Returns the fetched row.
         """
         next_uid = np.asarray(self._state.pool.next_uid)
         if next_uid.size and int(next_uid.max()) > slots.UID_LIMIT:
@@ -591,6 +616,7 @@ class StreamScheduler:
                 f"allocated ~2**31 track ids.  uids are int32 and only "
                 f"reset when the lane is recycled (reset_ragged); split "
                 f"the sequence or re-admit it to reset its uid namespace.")
+        return next_uid
 
     def pop_ready(self) -> list[SequenceTracks]:
         """Release every finished sequence whose turn has come (submission
@@ -695,7 +721,9 @@ class StreamScheduler:
             "forced_width": self._forced_width,
             "counters": {"frames_processed": self.frames_processed,
                          "lane_steps": self.lane_steps,
-                         "chunks_run": self.chunks_run},
+                         "chunks_run": self.chunks_run,
+                         "bytes_staged": self.bytes_staged,
+                         "bytes_fetched": self.bytes_fetched},
             "admissions": [list(a) for a in self.admissions],
             "seqs": {str(s.index): {"name": s.name} for s in live},
             "done": {str(i): self._ready.peek(i).name
@@ -845,6 +873,9 @@ class StreamScheduler:
         self.frames_processed = int(c["frames_processed"])
         self.lane_steps = int(c["lane_steps"])
         self.chunks_run = int(c["chunks_run"])
+        # snapshots written before the byte counters existed read 0
+        self.bytes_staged = int(c.get("bytes_staged", 0))
+        self.bytes_fetched = int(c.get("bytes_fetched", 0))
         self.admissions = [tuple(a) for a in meta["admissions"]]
 
     def drain(self) -> list[SequenceTracks]:

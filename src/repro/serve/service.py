@@ -35,12 +35,18 @@ import json
 import time
 from typing import Callable, Optional
 
+import jax
 import numpy as np
 
 from repro.ckpt import CheckpointManager, committed_steps, restore_flat
 from repro.data.stream import SequenceTracks
 
 SERVICE_META_KEY = "__service_meta__"
+
+# Profiler spans (``jax.profiler.TraceAnnotation``): ``svc.submit`` carries
+# ``seq=<submission index>`` (the index the submission takes, or would
+# have taken if shed), ``svc.checkpoint`` ``chunk=<step committed>``.
+SPANS = ("svc.submit", "svc.checkpoint")
 
 
 class Overloaded(Exception):
@@ -222,26 +228,30 @@ class TrackingService:
         Checks run cheapest-first: breaker state, the client's token
         bucket, then the queue bounds — a shed consumes no bucket token
         beyond the rate check itself and leaves no state behind."""
-        if self.breaker.state == CircuitBreaker.OPEN:
-            self._shed(client, "breaker_open",
-                       max(self.breaker.retry_after(), self.retry_after_hint))
-        bucket = self._bucket(client)
-        if bucket is not None:
-            wait = bucket.try_take()
-            if wait > 0.0:
-                self._shed(client, "rate", wait)
-        if self.pending >= self.max_pending:
-            self._shed(client, "queue", self.retry_after_hint)
-        if self._inflight.get(client, 0) >= self.per_client_pending:
-            self._shed(client, "client_queue", self.retry_after_hint)
-        idx = self.sched.submit(name, det_boxes, det_mask,
-                                det_class=det_class, det_embed=det_embed)
-        self._client_of[idx] = client
-        self._inflight[client] = self._inflight.get(client, 0) + 1
-        # zero-frame sequences finalize inside submit(); release them (and
-        # anything they unblocked) without waiting for a chunk dispatch.
-        self._deliver(self.sched.pop_ready())
-        return idx
+        with jax.profiler.TraceAnnotation("svc.submit",
+                                          seq=self.sched._num_submitted):
+            if self.breaker.state == CircuitBreaker.OPEN:
+                self._shed(client, "breaker_open",
+                           max(self.breaker.retry_after(),
+                               self.retry_after_hint))
+            bucket = self._bucket(client)
+            if bucket is not None:
+                wait = bucket.try_take()
+                if wait > 0.0:
+                    self._shed(client, "rate", wait)
+            if self.pending >= self.max_pending:
+                self._shed(client, "queue", self.retry_after_hint)
+            if self._inflight.get(client, 0) >= self.per_client_pending:
+                self._shed(client, "client_queue", self.retry_after_hint)
+            idx = self.sched.submit(name, det_boxes, det_mask,
+                                    det_class=det_class, det_embed=det_embed)
+            self._client_of[idx] = client
+            self._inflight[client] = self._inflight.get(client, 0) + 1
+            # zero-frame sequences finalize inside submit(); release them
+            # (and anything they unblocked) without waiting for a chunk
+            # dispatch.
+            self._deliver(self.sched.pop_ready())
+            return idx
 
     def _shed(self, client: str, reason: str, retry_after: float):
         self.sheds.append((client, reason, retry_after))
@@ -345,24 +355,26 @@ class TrackingService:
         silently (repro.ckpt contract)."""
         if self.ckpt is None:
             raise ValueError("service was constructed without ckpt_dir")
-        meta, arrays = self.sched.export_state()
-        smeta = {
-            "schema": 1,
-            "sched": meta,
-            "service": {
-                "next_result": self._next_result,
-                "client_of": {str(i): c
-                              for i, c in self._client_of.items()},
-            },
-        }
-        blob = np.frombuffer(json.dumps(smeta).encode(), np.uint8).copy()
-        tree = dict(arrays)
-        tree[SERVICE_META_KEY] = blob
-        step = self.sched.chunks_run
-        self.ckpt.save_async(step, tree)
-        if wait:
-            self.ckpt.wait()
-        return step
+        with jax.profiler.TraceAnnotation("svc.checkpoint",
+                                          chunk=self.sched.chunks_run):
+            meta, arrays = self.sched.export_state()
+            smeta = {
+                "schema": 1,
+                "sched": meta,
+                "service": {
+                    "next_result": self._next_result,
+                    "client_of": {str(i): c
+                                  for i, c in self._client_of.items()},
+                },
+            }
+            blob = np.frombuffer(json.dumps(smeta).encode(), np.uint8).copy()
+            tree = dict(arrays)
+            tree[SERVICE_META_KEY] = blob
+            step = self.sched.chunks_run
+            self.ckpt.save_async(step, tree)
+            if wait:
+                self.ckpt.wait()
+            return step
 
     def _rollback(self) -> None:
         """Re-import the latest committed checkpoint after a dispatch

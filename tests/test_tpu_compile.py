@@ -123,6 +123,24 @@ def test_served_kernel_compiles_for_v5e(one_chip, kernel, variant):
     assert mem.output_size_in_bytes >= state_bytes
 
 
+def test_chunk_kernel_op_is_named_fused_chunk_for_v5e(one_chip):
+    """The chunk kernel's custom call carries the name ``fused_chunk``
+    that the kernel passes to ``pallas_call``: profiles of the served
+    path tell the kernel's device time from the rest of the chunk
+    program by this op name."""
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, args, kw = _chunk_case(sd, "greedy")
+    hlo = jax.jit(lambda *a: fn(*a, block_s=BLOCK_S, **kw)
+                  ).lower(*args).compile().as_text()
+    calls = [line.split(" = ", 1)[0].split()[-1]
+             for line in hlo.splitlines() if "tpu_custom_call" in line
+             and " = " in line]
+    assert calls and all(re.fullmatch(r"%fused_chunk(\.\d+)?", c)
+                         for c in calls), calls
+
+
 def test_lane_hungarian_solver_indexes_by_select_for_v5e(one_chip):
     """The JV solver behind every Hungarian engine, vmapped over 2,048
     lanes: no gather or scatter in its compiled loops, which the TPU would
