@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import heapq
 from typing import Optional
 
 import jax
@@ -97,10 +98,10 @@ class _Seq:
     det_mask: np.ndarray           # [F, D]
     det_class: Optional[np.ndarray] = None   # [F, D] int32 (multi-class)
     det_embed: Optional[np.ndarray] = None   # [F, D, E] (embed costs)
-    boxes: list = dataclasses.field(default_factory=list)
-    uid: list = dataclasses.field(default_factory=list)
-    emit: list = dataclasses.field(default_factory=list)
-    cls: list = dataclasses.field(default_factory=list)
+    # whole-length outputs, allocated by the first chunk that steps the
+    # sequence: (boxes [F, T, 4], uid [F, T], emit [F, T][, cls [F, T]])
+    out: tuple = ()
+    filled: int = 0                # frames of ``out`` written so far
 
     @property
     def length(self) -> int:
@@ -221,6 +222,10 @@ class StreamScheduler:
         # construction width (elastic mode resizes between chunks).
         self.lane_steps = 0
         self.chunks_run = 0
+        # segments (one sequence's run of steps on one lane) planned:
+        # the host's per-chunk Python work, `frames_processed` the frames
+        # it covers
+        self.segments_planned = 0
         self.bytes_staged = 0          # chunk operands, host -> device
         self.bytes_fetched = 0         # chunk outputs + next_uid, back
         self.admissions: list[tuple[int, int]] = []  # (seq index, step)
@@ -484,7 +489,14 @@ class StreamScheduler:
         recycling — is planned before anything is dispatched.  While a
         shrink is evacuating, lanes at or beyond the target width take no
         new admissions (their occupants run to completion); queued
-        sequences keep admitting FIFO into the surviving lanes."""
+        sequences keep admitting FIFO into the surviving lanes.
+
+        The unit of the plan is a *segment* ``(t0, n, lane, seq, k0)``:
+        ``seq`` runs frames ``k0 .. k0+n-1`` on ``lane`` at chunk steps
+        ``t0 .. t0+n-1``, filled with one slice copy per operand.  Each
+        queued sequence is admitted at the earliest free ``(step, lane)``,
+        step-major and lane-minor, and a lane freed at step ``t`` admits
+        at ``t`` — the order of a step-by-step walk over every lane."""
         c, l, d = self.chunk, self.num_lanes, self.max_dets
         admit_limit = (l if self._shrink_target is None
                        else self._shrink_target)
@@ -496,33 +508,39 @@ class StreamScheduler:
         it = iter(extras)
         dc = next(it) if self._need_class else None
         de = next(it) if self._need_embed else None
-        mapping = []                                  # (t, lane, seq, frame)
-        for t in range(c):
-            for lane in range(l):
-                if self._occupant[lane] is None and self._pending \
-                        and lane < admit_limit:
-                    self._occupant[lane] = self._pending.popleft()
-                    self._cursor[lane] = 0
-                    reset[t, lane] = True             # recycle in this step
-                    self.admissions.append(
-                        (self._occupant[lane].index,
-                         self.chunks_run * self.chunk + t))
-                seq = self._occupant[lane]
-                if seq is None:
-                    continue
-                k = self._cursor[lane]
-                det[t, lane] = seq.det_boxes[k]
-                dm[t, lane] = seq.det_mask[k]
-                if dc is not None:
-                    dc[t, lane] = seq.det_class[k]
-                if de is not None:
-                    de[t, lane] = seq.det_embed[k]
-                active[t, lane] = True
-                mapping.append((t, lane, seq, k))
-                self._cursor[lane] = k + 1
-                if k + 1 == seq.length:               # lane free next step
-                    self._occupant[lane] = None
-        return det, dm, active, reset, extras, mapping
+        segments = []                          # (t0, n, lane, seq, k0)
+        free = []                              # heap of (step, lane)
+
+        def place(t0, lane, seq, k0):
+            n = min(seq.length - k0, c - t0)
+            t1, k1 = t0 + n, k0 + n
+            det[t0:t1, lane] = seq.det_boxes[k0:k1]
+            dm[t0:t1, lane] = seq.det_mask[k0:k1]
+            if dc is not None:
+                dc[t0:t1, lane] = seq.det_class[k0:k1]
+            if de is not None:
+                de[t0:t1, lane] = seq.det_embed[k0:k1]
+            active[t0:t1, lane] = True
+            segments.append((t0, n, lane, seq, k0))
+            self._cursor[lane] = k1
+            if k1 == seq.length:               # lane free from step t1
+                self._occupant[lane] = None
+                if t1 < c and lane < admit_limit:
+                    heapq.heappush(free, (t1, lane))
+
+        for lane in range(l):
+            seq = self._occupant[lane]
+            if seq is not None:
+                place(0, lane, seq, self._cursor[lane])
+            elif lane < admit_limit:
+                heapq.heappush(free, (0, lane))
+        while free and self._pending:
+            t, lane = heapq.heappop(free)
+            seq = self._occupant[lane] = self._pending.popleft()
+            reset[t, lane] = True                 # recycle in this step
+            self.admissions.append((seq.index, self.chunks_run * c + t))
+            place(t, lane, seq, 0)
+        return det, dm, active, reset, extras, segments
 
     # ------------------------------------------------------------ execution
     def _run_chunk(self) -> list[SequenceTracks]:
@@ -533,7 +551,7 @@ class StreamScheduler:
         span = jax.profiler.TraceAnnotation
         with span("sched.plan", chunk=n):
             self._maybe_resize()
-            det, dm, active, reset, extras, mapping = self._plan_chunk()
+            det, dm, active, reset, extras, segments = self._plan_chunk()
         staged = (det, dm, active, reset) + extras
         with span("sched.stage", chunk=n):
             if self._sharding is not None:
@@ -548,18 +566,20 @@ class StreamScheduler:
             emit = np.asarray(outs.emit)
             cls = np.asarray(outs.cls) if self._need_class else None
         with span("sched.unpack", chunk=n):
+            fetched = (boxes, uid, emit) + ((cls,) if cls is not None else ())
             finished = []
-            for t, lane, seq, k in mapping:
-                # copies, so buffering a row doesn't pin the whole chunk
-                # array until a long-running neighbour sequence finalizes
-                seq.boxes.append(boxes[t, lane].copy())
-                seq.uid.append(uid[t, lane].copy())
-                seq.emit.append(emit[t, lane].copy())
-                if cls is not None:
-                    seq.cls.append(cls[t, lane].copy())
-                if k + 1 == seq.length:
+            for t0, m, lane, seq, k0 in segments:
+                if k0 == 0:
+                    seq.out = tuple(np.empty((seq.length,) + a.shape[2:],
+                                             a.dtype) for a in fetched)
+                # into the sequence's own buffers, which pin no chunk array
+                for buf, a in zip(seq.out, fetched):
+                    buf[k0:k0 + m] = a[t0:t0 + m, lane]
+                seq.filled = k0 + m
+                if seq.filled == seq.length:
                     finished.append(seq)
-            self.frames_processed += len(mapping)
+            self.frames_processed += sum(seg[1] for seg in segments)
+            self.segments_planned += len(segments)
             # denominator from the planned schedule, not the raw chunk
             # size: fully-idle tail steps of a draining chunk carry no
             # lanes' work
@@ -574,25 +594,20 @@ class StreamScheduler:
                 self._finalize(seq)
             return self._ready.pop_ready()
 
-    def _finalize(self, seq: _Seq) -> None:
+    def _no_frames(self) -> tuple:
+        """The outputs of a sequence with no frame filled, in the order
+        of ``_Seq.out``."""
         t = self.engine.config.max_trackers
+        out = (np.zeros((0, t, 4), np.float32), np.zeros((0, t), np.int32),
+               np.zeros((0, t), bool))
+        return out + ((np.zeros((0, t), np.int32),) if self._need_class
+                      else ())
+
+    def _finalize(self, seq: _Seq) -> None:
+        boxes, uid, emit, *cls = seq.out or self._no_frames()
         self._ready.put(seq.index, SequenceTracks(
-            name=seq.name,
-            boxes=(np.stack(seq.boxes) if seq.boxes
-                   else np.zeros((0, t, 4), np.float32)),
-            uid=(np.stack(seq.uid) if seq.uid
-                 else np.zeros((0, t), np.int32)),
-            emit=(np.stack(seq.emit) if seq.emit
-                  else np.zeros((0, t), bool)),
-            cls=((np.stack(seq.cls) if seq.cls
-                  else np.zeros((0, t), np.int32))
-                 if self._need_class else None),
-        ))
-        # free the per-frame rows now that they are stacked: a full-lane
-        # chunk finishes ~65k rows, and freeing them is release work (on a
-        # v5e host ~10% of a chunk), not work of whatever drops the seq
-        for rows in (seq.boxes, seq.uid, seq.emit, seq.cls):
-            rows.clear()
+            name=seq.name, boxes=boxes, uid=uid, emit=emit,
+            cls=cls[0] if cls else None))
 
     def _check_uid_headroom(self) -> np.ndarray:
         """Guard the per-lane int32 uid counter (``SlotPool.next_uid``).
@@ -665,26 +680,19 @@ class StreamScheduler:
             state = self._state
         return jax.tree.map(np.asarray, jax.device_get(state))
 
+    _OUT_NAMES = ("out_boxes", "out_uid", "out_emit", "out_cls")
+
     def _seq_arrays(self, seq: _Seq) -> dict:
-        t = self.engine.config.max_trackers
         pre = f"seq/{seq.index}"
-        arrays = {
-            f"{pre}/det_boxes": seq.det_boxes,
-            f"{pre}/det_mask": seq.det_mask,
-            f"{pre}/out_boxes": (np.stack(seq.boxes) if seq.boxes
-                                 else np.zeros((0, t, 4), np.float32)),
-            f"{pre}/out_uid": (np.stack(seq.uid) if seq.uid
-                               else np.zeros((0, t), np.int32)),
-            f"{pre}/out_emit": (np.stack(seq.emit) if seq.emit
-                                else np.zeros((0, t), bool)),
-        }
+        arrays = {f"{pre}/det_boxes": seq.det_boxes,
+                  f"{pre}/det_mask": seq.det_mask}
+        # the filled prefix: frames already written are never rewritten
+        for name, buf in zip(self._OUT_NAMES, seq.out or self._no_frames()):
+            arrays[f"{pre}/{name}"] = buf[:seq.filled]
         if seq.det_class is not None:
             arrays[f"{pre}/det_class"] = seq.det_class
         if seq.det_embed is not None:
             arrays[f"{pre}/det_embed"] = seq.det_embed
-        if self._need_class:
-            arrays[f"{pre}/out_cls"] = (np.stack(seq.cls) if seq.cls
-                                        else np.zeros((0, t), np.int32))
         return arrays
 
     def export_state(self) -> tuple[dict, dict]:
@@ -722,6 +730,7 @@ class StreamScheduler:
             "counters": {"frames_processed": self.frames_processed,
                          "lane_steps": self.lane_steps,
                          "chunks_run": self.chunks_run,
+                         "segments_planned": self.segments_planned,
                          "bytes_staged": self.bytes_staged,
                          "bytes_fetched": self.bytes_fetched},
             "admissions": [list(a) for a in self.admissions],
@@ -773,11 +782,14 @@ class StreamScheduler:
                               else np.asarray(dc, np.int32)),
                    det_embed=(None if de is None
                               else np.asarray(de, np.float32)))
-        seq.boxes = [np.array(a) for a in arrays[f"{pre}/out_boxes"]]
-        seq.uid = [np.array(a) for a in arrays[f"{pre}/out_uid"]]
-        seq.emit = [np.array(a) for a in arrays[f"{pre}/out_emit"]]
-        if self._need_class:
-            seq.cls = [np.array(a) for a in arrays[f"{pre}/out_cls"]]
+        names = self._OUT_NAMES[:4 if self._need_class else 3]
+        prefix = [np.asarray(arrays[f"{pre}/{name}"]) for name in names]
+        seq.filled = len(prefix[0])
+        if seq.filled:
+            seq.out = tuple(np.empty((seq.length,) + a.shape[1:], a.dtype)
+                            for a in prefix)
+            for buf, a in zip(seq.out, prefix):
+                buf[:seq.filled] = a
         return seq
 
     def import_state(self, meta: dict, arrays: dict) -> None:
@@ -873,7 +885,8 @@ class StreamScheduler:
         self.frames_processed = int(c["frames_processed"])
         self.lane_steps = int(c["lane_steps"])
         self.chunks_run = int(c["chunks_run"])
-        # snapshots written before the byte counters existed read 0
+        # snapshots written before these counters existed read 0
+        self.segments_planned = int(c.get("segments_planned", 0))
         self.bytes_staged = int(c.get("bytes_staged", 0))
         self.bytes_fetched = int(c.get("bytes_fetched", 0))
         self.admissions = [tuple(a) for a in meta["admissions"]]

@@ -426,3 +426,239 @@ def test_import_rejects_mismatched_engine_and_width():
     with pytest.raises(ValueError, match="missing device-state"):
         StreamScheduler(_engine(False), num_lanes=2, chunk=8).import_state(
             meta, broken)
+
+
+# ----------------------------------------------- segment planner parity
+def _lane_step_plan(sched):
+    """The planner as it was before segments, kept as the oracle: one
+    pass over every (step, lane), step-major, admitting FIFO into each
+    free lane below the admission limit and copying one frame a
+    lane-step.  Returns the operands and the ``(t, lane, seq index,
+    frame)`` of every lane-step that carries a frame."""
+    c, l, d = sched.chunk, sched.num_lanes, sched.max_dets
+    admit_limit = (l if sched._shrink_target is None
+                   else sched._shrink_target)
+    det = np.zeros((c, l, d, 4), np.float32)
+    dm = np.zeros((c, l, d), bool)
+    active = np.zeros((c, l), bool)
+    reset = np.zeros((c, l), bool)
+    extras = sched._zero_extras(c, l, d)
+    it = iter(extras)
+    dc = next(it) if sched._need_class else None
+    de = next(it) if sched._need_embed else None
+    steps = []
+    for t in range(c):
+        for lane in range(l):
+            if sched._occupant[lane] is None and sched._pending \
+                    and lane < admit_limit:
+                sched._occupant[lane] = sched._pending.popleft()
+                sched._cursor[lane] = 0
+                reset[t, lane] = True
+                sched.admissions.append((sched._occupant[lane].index,
+                                         sched.chunks_run * c + t))
+            seq = sched._occupant[lane]
+            if seq is None:
+                continue
+            k = sched._cursor[lane]
+            det[t, lane] = seq.det_boxes[k]
+            dm[t, lane] = seq.det_mask[k]
+            if dc is not None:
+                dc[t, lane] = seq.det_class[k]
+            if de is not None:
+                de[t, lane] = seq.det_embed[k]
+            active[t, lane] = True
+            steps.append((t, lane, seq.index, k))
+            sched._cursor[lane] = k + 1
+            if k + 1 == seq.length:
+                sched._occupant[lane] = None
+    return det, dm, active, reset, extras, steps
+
+
+EMBED = 4
+
+
+def _plan_engine(multiclass):
+    from repro.core import cost as cost_mod
+    extra = (dict(num_classes=3, cost=cost_mod.iou_embed(EMBED))
+             if multiclass else {})
+    return SortEngine(SortConfig(max_trackers=8, max_detections=MAX_DETS,
+                                 **extra))
+
+
+def _random_submission(rng, frames, multiclass):
+    d = int(rng.integers(1, MAX_DETS + 1))        # padded by submit
+    xy = rng.uniform(0, 200, (frames, d, 2)).astype(np.float32)
+    kw = {}
+    if multiclass:
+        kw = dict(det_class=rng.integers(0, 3, (frames, d)),
+                  det_embed=rng.random((frames, d, EMBED)).astype(
+                      np.float32))
+    return (np.concatenate([xy, xy + 20], -1), rng.random((frames, d)) < 0.6,
+            kw)
+
+
+@pytest.mark.parametrize("multiclass", [False, True],
+                         ids=["single_class", "class_embed"])
+@pytest.mark.parametrize("case", ["ragged", "one_lane", "elastic_shrink"])
+def test_segment_plan_matches_lane_step_oracle(case, multiclass):
+    """The segment planner writes byte-identical operands, admissions and
+    lane bookkeeping to the lane-step walk, chunk after chunk: seeded
+    ragged arrivals of 0 to 3 x chunk frames, a queue longer than the
+    lanes, and (elastic) a pinned shrink that evacuates while the queue
+    keeps admitting into the surviving lanes."""
+    chunk = 6
+    rng = np.random.default_rng(["ragged", "one_lane",
+                                 "elastic_shrink"].index(case))
+
+    def make():
+        eng = _plan_engine(multiclass)
+        if case == "elastic_shrink":
+            return StreamScheduler(eng, min_lanes=2, max_lanes=8,
+                                   num_lanes=8, chunk=chunk,
+                                   precompile=False)
+        return StreamScheduler(eng, num_lanes=1 if case == "one_lane" else 5,
+                               chunk=chunk)
+
+    seg, ref = make(), make()
+    evacuated_while_queued = False
+    for n in range(14):
+        for _ in range(int(rng.integers(0, 5)) if n < 10 else 0):
+            db, dm, kw = _random_submission(
+                rng, int(rng.integers(0, 3 * chunk + 1)), multiclass)
+            for s in (seg, ref):
+                s.submit(f"r{s._num_submitted}", db, dm, **kw)
+        if case == "elastic_shrink" and n == 3:
+            for s in (seg, ref):
+                s.request_width(2)
+        for s in (seg, ref):
+            s._maybe_resize()
+        assert seg.num_lanes == ref.num_lanes
+        evacuated_while_queued |= (seg._shrink_target is not None
+                                   and bool(seg._pending))
+        *got, segments = seg._plan_chunk()
+        *want, steps = _lane_step_plan(ref)
+        for a, b in zip(got[:4] + list(got[4]), want[:4] + list(want[4])):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            assert a.tobytes() == b.tobytes(), f"chunk {n}"
+        assert len(got[4]) == len(want[4]) == len(seg._extra_ndims)
+        assert sorted((t0 + i, lane, s.index, k0 + i)
+                      for t0, m, lane, s, k0 in segments
+                      for i in range(m)) == sorted(steps)
+        assert seg.admissions == ref.admissions
+        assert seg._cursor == ref._cursor
+        assert ([o and o.index for o in seg._occupant]
+                == [o and o.index for o in ref._occupant])
+        assert ([p.index for p in seg._pending]
+                == [p.index for p in ref._pending])
+        seg.chunks_run += 1
+        ref.chunks_run += 1
+    assert any(step % chunk for _, step in seg.admissions)  # mid-chunk
+    assert len(seg.admissions) > seg.ladder[-1]             # queue waited
+    if case == "elastic_shrink":
+        assert evacuated_while_queued
+        assert seg.num_lanes == 2 and seg.resizes[-1][1:] == (8, 2)
+
+
+# ----------------------------------------- checkpoints and segment counter
+def test_export_mid_sequence_holds_exactly_the_filled_frames():
+    """A snapshot taken mid-sequence carries the frames filled so far and
+    no more (queued sequences none), equal to the solo run's first
+    frames; the import continues bit-exactly."""
+    eng = _engine(True)
+    seqs = [(f"s{i}", *_scene(i, frames=f))
+            for i, f in enumerate([17, 30, 9, 23])]
+    sched = StreamScheduler(eng, num_lanes=2, chunk=8)
+    for name, db, dm in seqs:
+        sched.submit(name, db, dm)
+    results = []
+    for _ in range(2):
+        results.extend(sched.run_chunk())
+    meta, arrays = sched.export_state()
+    filled = {i: c for i, c in zip(meta["occupant"], meta["cursor"])
+              if i is not None}
+    assert filled == {0: 16, 1: 16}
+    for i in meta["pending"]:
+        filled[i] = 0
+    assert sorted(filled) == [0, 1, 2, 3]
+    for i, k in filled.items():
+        solo = _solo_run(eng, *seqs[i][1:])
+        for name, full in (("boxes", solo.boxes), ("uid", solo.uid),
+                           ("emit", solo.emit)):
+            got = arrays[f"seq/{i}/out_{name}"]
+            assert got.shape[0] == k
+            np.testing.assert_array_equal(got, np.asarray(full[:k, 0]))
+
+    fresh = StreamScheduler(_engine(True), num_lanes=2, chunk=8)
+    fresh.import_state(meta, arrays)
+    while fresh.busy:
+        results.extend(fresh.run_chunk())
+    assert [t.name for t in results] == [n for n, _, _ in seqs]
+    for (name, db, dm), tracks in zip(seqs, results):
+        _assert_tracks_equal_solo(tracks, _solo_run(eng, db, dm), name)
+
+
+def test_import_snapshot_of_stacked_rows_without_segment_counter():
+    """A snapshot in the earlier format — each sequence's outputs stacked
+    from a list of per-frame rows, no ``segments_planned`` counter —
+    still imports and continues bit-exactly."""
+    eng = _engine(True)
+    seqs = [(f"s{i}", *_scene(i, frames=f))
+            for i, f in enumerate([17, 30, 9, 23])]
+    sched = StreamScheduler(eng, num_lanes=2, chunk=8)
+    for name, db, dm in seqs:
+        sched.submit(name, db, dm)
+    results = []
+    for _ in range(2):
+        results.extend(sched.run_chunk())
+    meta, arrays = sched.export_state()
+    del meta["counters"]["segments_planned"]
+    t = eng.config.max_trackers
+    empty = {"out_boxes": ((0, t, 4), np.float32),
+             "out_uid": ((0, t), np.int32), "out_emit": ((0, t), bool)}
+    older = dict(arrays)
+    for key, a in arrays.items():
+        name = key.rsplit("/", 1)[-1]
+        if key.startswith("seq/") and name in empty:
+            rows = [np.array(r) for r in a]
+            older[key] = (np.stack(rows) if rows
+                          else np.zeros(*empty[name]))
+    fresh = StreamScheduler(_engine(True), num_lanes=2, chunk=8)
+    fresh.import_state(meta, older)
+    assert fresh.segments_planned == 0
+    while fresh.busy:
+        results.extend(fresh.run_chunk())
+    assert [r.name for r in results] == [n for n, _, _ in seqs]
+    for (name, db, dm), tracks in zip(seqs, results):
+        _assert_tracks_equal_solo(tracks, _solo_run(eng, db, dm), name)
+
+
+def test_segments_planned_counts_lane_runs_and_round_trips():
+    """On a hand-built schedule ``segments_planned`` is the number of
+    (lane, sequence) runs of each chunk, and it crosses a checkpoint.
+
+    Two lanes, chunk 4, lengths 6, 3, 2, 5: chunk 0 runs s0 on lane 0,
+    s1 then s2 (admitted at step 3) on lane 1; chunk 1 runs s0 on lane 0,
+    s2 then s3 (admitted at step 5) on lane 1; chunk 2 runs s3."""
+    eng = _engine(True)
+    lengths = [6, 3, 2, 5]
+    seqs = [(f"h{i}", *_scene(50 + i, f)) for i, f in enumerate(lengths)]
+    sched = StreamScheduler(eng, num_lanes=2, chunk=4)
+    for name, db, dm in seqs:
+        sched.submit(name, db, dm)
+    counts, results = [], []
+    for _ in range(2):
+        results.extend(sched.run_chunk())
+        counts.append(sched.segments_planned)
+    assert counts == [3, 6]
+    meta, arrays = sched.export_state()
+    assert meta["counters"]["segments_planned"] == 6
+    again = StreamScheduler(_engine(True), num_lanes=2, chunk=4)
+    again.import_state(meta, arrays)
+    assert again.segments_planned == 6
+    results.extend(again.drain())
+    assert again.segments_planned == 7
+    assert again.admissions == [(0, 0), (1, 0), (2, 3), (3, 5)]
+    assert again.frames_processed == sum(lengths)
+    assert [r.name for r in results] == ["h0", "h1", "h2", "h3"]
+    for (name, db, dm), tracks in zip(seqs, results):
+        _assert_tracks_equal_solo(tracks, _solo_run(eng, db, dm), name)

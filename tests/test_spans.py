@@ -9,8 +9,10 @@ the device's operations; these tests read them back from a CPU profile.
 the device and the results moved back, which follow from the shapes.
 """
 import asyncio
+import gc
 import glob
 import os
+import weakref
 
 import jax
 import numpy as np
@@ -149,12 +151,17 @@ def test_checkpointing_service_writes_one_span_per_commit(tmp_path):
 
 
 def test_release_frees_the_finished_rows():
+    """A released sequence's arrays are exactly its length, and the
+    scheduler keeps nothing of it once it is released."""
     svc = _service()
     _submit(svc, 0, 3)
-    seq = svc.sched._pending[0]
+    seq = weakref.ref(svc.sched._pending[0])
     asyncio.run(svc.step())                 # 3 frames: done in one chunk
-    assert svc.completed[0].boxes.shape[0] == 3
-    assert seq.boxes == seq.uid == seq.emit == seq.cls == []
+    tracks = svc.completed[0]
+    assert tracks.boxes.shape == (3, T, 4)
+    assert tracks.uid.shape == tracks.emit.shape == (3, T)
+    gc.collect()
+    assert seq() is None
 
 
 def _expected_bytes(multiclass):
