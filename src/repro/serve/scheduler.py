@@ -58,8 +58,10 @@ from repro.core.sort import SortEngine, lane_state_of, sort_state_of
 from repro.data.stream import ReorderBuffer, SequenceTracks
 
 # Profiler spans (``jax.profiler.TraceAnnotation``) of one dispatched
-# chunk, in the order it runs them; each carries the stat ``chunk=<n>``,
-# the chunk's number (``chunks_run`` before it).
+# chunk, in the order it runs them; each carries the stats ``chunk=<n>``,
+# the chunk's number (``chunks_run`` before it), and ``shards=<n>``, the
+# devices of the lane mesh (1 without one).  In mesh mode ``sched.stage``
+# holds ``sharding/lanes.py``'s ``lanes.place``.
 SPANS = ("sched.plan", "sched.stage", "sched.fetch", "sched.unpack",
          "sched.release")
 
@@ -548,24 +550,28 @@ class StreamScheduler:
             # nothing to dispatch — only buffered completions to release
             return self._ready.pop_ready()
         n = self.chunks_run
-        span = jax.profiler.TraceAnnotation
-        with span("sched.plan", chunk=n):
+        shards = 1 if self._sharding is None else self._sharding.shard_count
+
+        def span(name):
+            return jax.profiler.TraceAnnotation(name, chunk=n, shards=shards)
+
+        with span("sched.plan"):
             self._maybe_resize()
             det, dm, active, reset, extras, segments = self._plan_chunk()
         staged = (det, dm, active, reset) + extras
-        with span("sched.stage", chunk=n):
+        with span("sched.stage"):
             if self._sharding is not None:
                 operands = self._sharding.place(*staged)
             else:
                 operands = tuple(jnp.asarray(a) for a in staged)
             self._state, outs = self._chunk_fn(self._state, *operands)
-        with span("sched.fetch", chunk=n):
+        with span("sched.fetch"):
             next_uid = self._check_uid_headroom()
             boxes = np.asarray(outs.boxes)            # [C, L, T, 4]
             uid = np.asarray(outs.uid)
             emit = np.asarray(outs.emit)
             cls = np.asarray(outs.cls) if self._need_class else None
-        with span("sched.unpack", chunk=n):
+        with span("sched.unpack"):
             fetched = (boxes, uid, emit) + ((cls,) if cls is not None else ())
             finished = []
             for t0, m, lane, seq, k0 in segments:
@@ -589,7 +595,7 @@ class StreamScheduler:
             self.bytes_fetched += sum(a.nbytes for a in
                                       (next_uid, boxes, uid, emit, cls)
                                       if a is not None)
-        with span("sched.release", chunk=n):
+        with span("sched.release"):
             for seq in finished:
                 self._finalize(seq)
             return self._ready.pop_ready()

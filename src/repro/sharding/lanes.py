@@ -47,10 +47,15 @@ from repro.core import kalman, slots
 from repro.core.sort import (LaneSortState, SortOutput, SortState,
                              lane_state_of, resize_streams, sort_state_of)
 
-from .specs import LANE_AXIS, lane_dim_spec, named
+from .specs import LANE_AXIS, lane_dim_spec
 
 __all__ = ["LANE_AXIS", "MeshLaneState", "LaneSharding", "lane_mesh",
            "shard_count", "state_pspecs"]
+
+# Profiler spans (``jax.profiler.TraceAnnotation``): ``lanes.place``
+# covers :meth:`LaneSharding.place`, inside the scheduler's
+# ``sched.stage``; it carries ``shards=<n>``.
+SPANS = ("lanes.place",)
 
 
 def lane_mesh(num_devices: Optional[int] = None, *, devices=None) -> Mesh:
@@ -146,6 +151,18 @@ def _chunk_spec(ndim: int) -> P:
     return lane_dim_spec(ndim, 1)
 
 
+def _placement(state, specs, mesh: Mesh):
+    """The ``NamedSharding`` each state leaf is placed with: its spec,
+    except a zero-size leaf (``embed`` of a cost without an embedding
+    term), which is replicated.  XLA returns every zero-size output of the
+    chunk program replicated whatever its out-spec, so placing it so from
+    the start gives the program the same input shardings on every chunk:
+    it compiles once, not again at the second chunk."""
+    return jax.tree.map(
+        lambda a, spec: NamedSharding(mesh, P() if a.size == 0 else spec),
+        state, specs)
+
+
 class LaneSharding:
     """``lanes -> mesh`` sharding layer for the stream scheduler.
 
@@ -196,7 +213,8 @@ class LaneSharding:
         else:
             state = self.engine.init(self.num_lanes)
         self._state_specs = state_pspecs(state)
-        return jax.device_put(state, named(self._state_specs, self.mesh))
+        return jax.device_put(
+            state, _placement(state, self._state_specs, self.mesh))
 
     # ------------------------------------------------------------ chunk fn
     def shard_chunk(self, chunk_body, extra_operand_ndims=()):
@@ -300,8 +318,8 @@ class LaneSharding:
         this mesh (DESIGN.md §11) and the commit half of :meth:`migrate`."""
         new_state = self._from_engine(eng_state)
         self._state_specs = state_pspecs(new_state)
-        return jax.device_put(new_state,
-                              named(self._state_specs, self.mesh))
+        return jax.device_put(
+            new_state, _placement(new_state, self._state_specs, self.mesh))
 
     # ----------------------------------------------------------- placement
     def place(self, det, dm, active, reset, *extras):
@@ -314,7 +332,9 @@ class LaneSharding:
         the same lane-on-dim-1 rule.
         """
         arrs = (det, dm, active, reset) + extras
-        return tuple(
-            jax.device_put(np.asarray(a),
-                           NamedSharding(self.mesh, _chunk_spec(a.ndim)))
-            for a in arrs)
+        with jax.profiler.TraceAnnotation("lanes.place",
+                                          shards=self.shard_count):
+            return tuple(
+                jax.device_put(np.asarray(a),
+                               NamedSharding(self.mesh, _chunk_spec(a.ndim)))
+                for a in arrs)

@@ -382,6 +382,14 @@ def test_migrated_state_stays_lane_sharded():
         specs, is_leaf=lambda x: isinstance(x, PartitionSpec))
     for leaf, spec in zip(jax.tree.leaves(el._state), spec_leaves):
         assert isinstance(leaf.sharding, NamedSharding), leaf.shape
+        if leaf.size == 0:
+            # the embed block of a cost without one holds no data to shard;
+            # it is placed replicated, as XLA returns it from every chunk
+            # (test_device_sharding.py::
+            # test_state_stays_lane_sharded_across_chunks)
+            assert leaf.shape == (0, eng.config.max_trackers,
+                                  el._state.frame_count.shape[0])
+            continue
         assert leaf.sharding.spec == spec, (leaf.shape, leaf.sharding.spec)
 
 

@@ -244,11 +244,21 @@ def test_state_stays_lane_sharded_across_chunks():
     seqs = [(f"r{i}", *_scene(60 + i, f)) for i, f in enumerate([9, 4, 7])]
     eng = _engine(True)
     sched, _ = _serve(eng, seqs, mesh=lane_mesh(4))
+    assert sched.chunks_run >= 3 and len(sched.trace_log) == 1
     specs = state_pspecs(sched._state)
     spec_leaves = jax.tree.leaves(
         specs, is_leaf=lambda x: isinstance(x, PartitionSpec))
     for leaf, spec in zip(jax.tree.leaves(sched._state), spec_leaves):
         assert isinstance(leaf.sharding, NamedSharding), leaf.shape
+        if leaf.size == 0:
+            # the embed block of a cost without one, [0, T, L]: it holds no
+            # data to shard, and XLA returns a zero-size output replicated
+            # whatever its out-spec, so the sharding layer places it so
+            # from the start (lanes._placement) and the chunk program
+            # compiles once; only its shape is the state's
+            assert leaf.shape == (0, eng.config.max_trackers,
+                                  sched._state.frame_count.shape[0])
+            continue
         assert leaf.sharding.spec == spec, (leaf.shape, leaf.sharding.spec)
 
 

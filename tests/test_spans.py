@@ -22,6 +22,7 @@ from jax.profiler import ProfileData
 from repro.core import cost as cost_mod
 from repro.core.sort import SortConfig, SortEngine
 from repro.serve import StreamScheduler, TrackingService, scheduler, service
+from repro.sharding import lane_mesh, lanes
 
 T, D, LANES, CHUNK, EMBED = 8, 5, 3, 4, 4
 
@@ -44,9 +45,9 @@ def _seq(i, frames, multiclass=False):
     return (f"s{i}", boxes, mask), kw
 
 
-def _service(multiclass=False, **knobs):
+def _service(multiclass=False, mesh=None, **knobs):
     sched = StreamScheduler(_engine(multiclass), num_lanes=LANES,
-                            max_dets=D, chunk=CHUNK)
+                            max_dets=D, chunk=CHUNK, mesh=mesh)
     return TrackingService(sched, **knobs)
 
 
@@ -55,10 +56,14 @@ def _submit(svc, i, frames, multiclass=False):
     return asyncio.run(svc.submit(*args, **kw))
 
 
+# each module's spans, by the prefix every one of its names carries
+MODULES = {"sched.": scheduler, "svc.": service, "lanes.": lanes}
+
+
 def _profiled(tmp_path, fn):
     """Run ``fn`` under the profiler; return the program's spans in start
-    order as ``(name, start_ns, end_ns, stats)``."""
-    names = set(scheduler.SPANS) | set(service.SPANS)
+    order as ``(name, start_ns, end_ns, stats)``: every host event named
+    with one of the program's prefixes."""
     with jax.profiler.trace(str(tmp_path)):
         fn()
     path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
@@ -67,15 +72,15 @@ def _profiled(tmp_path, fn):
              for plane in ProfileData.from_file(path).planes
              if plane.name.startswith("/host:")
              for line in plane.lines for e in line.events
-             if e.name in names]
+             if e.name.startswith(tuple(MODULES))]
     return sorted(spans, key=lambda s: s[1])
 
 
 def test_span_names_are_distinct_and_layered():
-    names = scheduler.SPANS + service.SPANS
-    assert len(set(names)) == len(names) == 7
-    assert all(n.startswith("sched.") for n in scheduler.SPANS)
-    assert all(n.startswith("svc.") for n in service.SPANS)
+    names = scheduler.SPANS + service.SPANS + lanes.SPANS
+    assert len(set(names)) == len(names) == 8
+    for prefix, module in MODULES.items():
+        assert all(n.startswith(prefix) for n in module.SPANS)
 
 
 def test_each_chunk_writes_its_spans_in_order(tmp_path):
@@ -90,11 +95,40 @@ def test_each_chunk_writes_its_spans_in_order(tmp_path):
     spans = _profiled(tmp_path, serve)
     sched = [s for s in spans if s[0] in scheduler.SPANS]
     assert [s[0] for s in sched] == list(scheduler.SPANS) * 3
-    assert [s[3] for s in sched] == [{"chunk": n} for n in range(3)
+    assert [s[3] for s in sched] == [{"chunk": n, "shards": 1}
+                                     for n in range(3)
                                      for _ in scheduler.SPANS]
     # one after the other, none nested in another
     for a, b in zip(sched, sched[1:]):
         assert a[2] <= b[1]
+    # without a mesh nothing is placed by the sharding layer
+    assert not [s for s in spans if s[0] in lanes.SPANS]
+
+
+def test_mesh_chunk_places_its_operands_inside_the_stage(tmp_path):
+    """On a lane mesh (of the one device a test process has) each chunk
+    writes one ``lanes.place`` inside its ``sched.stage``, and every span
+    says how many devices the mesh holds."""
+    svc = _service(mesh=lane_mesh(1))
+    for i, f in enumerate((6, 3, 9)):
+        _submit(svc, i, f)
+
+    def serve():
+        for _ in range(3):
+            asyncio.run(svc.step())
+
+    spans = _profiled(tmp_path, serve)
+    for name, *_ in spans:
+        assert name in MODULES[name.split(".")[0] + "."].SPANS, name
+    stages = [s for s in spans if s[0] == "sched.stage"]
+    places = [s for s in spans if s[0] == "lanes.place"]
+    assert len(stages) == len(places) == svc.sched.chunks_run == 3
+    for stage, place in zip(stages, places):
+        assert stage[1] <= place[1] and place[2] <= stage[2]
+        assert place[3] == {"shards": 1}
+    sched = [s for s in spans if s[0] in scheduler.SPANS]
+    assert [s[0] for s in sched] == list(scheduler.SPANS) * 3
+    assert all(s[3]["shards"] == 1 for s in sched)
 
 
 def test_no_step_work_writes_no_chunk_spans(tmp_path):

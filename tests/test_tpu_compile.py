@@ -188,3 +188,47 @@ def test_sharded_service_chunk_compiles_for_four_chips(topo, monkeypatch):
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     assert [c for c in COLLECTIVES if c in hlo] == []
+
+
+def test_mesh_chunk_program_returns_the_state_as_placed(topo, monkeypatch):
+    """``mot15-sort``'s chunk program (chunk kernel, Hungarian pre-pass)
+    over a 4-chip ("lanes",) mesh, with the state placed as
+    ``LaneSharding`` places it: the chip's compiler returns every state
+    leaf with the sharding it went in with, the zero-size ``embed`` block
+    included, so the next chunk runs the same program and nothing
+    compiles again; the kernel is in it and no collective is."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    mesh = Mesh(np.asarray(topo.devices), (lanes_mod.LANE_AXIS,))
+    eng = SortEngine(SortConfig(max_trackers=T, max_detections=D,
+                                use_kernels=True, chunk_kernel=True,
+                                assoc="hungarian"))
+    eng._block_s = BLOCK_S
+    lanes = len(topo.devices) * LANES
+    sharding = lanes_mod.LaneSharding(eng, mesh, lanes)
+    abstract = jax.eval_shape(sharding.init)
+    placed = lanes_mod._placement(abstract, lanes_mod.state_pspecs(abstract),
+                                  mesh)
+    state = jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=s), abstract, placed)
+    assert state.embed.shape[0] == 0
+
+    def operand(shape, dtype):
+        spec = lane_dim_spec(len(shape), 1)
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    compiled = jax.jit(sharding.shard_chunk(eng.run_chunk_ragged)).lower(
+        state, operand((FRAMES, lanes, D, 4), jnp.float32),
+        operand((FRAMES, lanes, D), jnp.bool_),
+        operand((FRAMES, lanes), jnp.bool_),
+        operand((FRAMES, lanes), jnp.bool_)).compile()
+    state_in = jax.tree.leaves(compiled.input_shardings[0][0])
+    state_out = jax.tree.leaves(compiled.output_shardings[0])
+    assert len(state_in) == len(state_out) == len(jax.tree.leaves(placed))
+    for leaf, a, b, want in zip(jax.tree.leaves(abstract), state_in,
+                                state_out, jax.tree.leaves(placed)):
+        assert a.is_equivalent_to(want, leaf.ndim), leaf.shape
+        assert b.is_equivalent_to(want, leaf.ndim), leaf.shape
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert [c for c in COLLECTIVES if c in hlo] == []
